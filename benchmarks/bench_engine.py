@@ -4,8 +4,8 @@ First entry in the repo's perf trajectory (PR 1): measures the partially-
 evaluated fast path (``Machine(specialize=True)`` — opcode-set-specialized
 slots, compact SEND capture, chunked K-Vcycle dispatch) against the seed
 engine (``specialize=False`` — compute-all-select, full [T, C] trace,
-per-Vcycle while_loop), plus the Pallas chunk kernel in interpret mode and
-the vectorized numpy ISA simulator.
+per-Vcycle while_loop), plus the Pallas chunk kernel (interpret mode on a
+CPU backend) and the vectorized numpy ISA simulator.
 
 Emits ``results/bench/BENCH_engine.json`` and a copy at the repo root
 (``BENCH_engine.json``) so the trajectory is easy to diff across PRs. Rows
@@ -79,10 +79,10 @@ def bench_circuit(nm: str, scale: str = "full", reps: int = REPS) -> dict:
                               / row["seed_vcycles_per_s"])
     row["isasim_vcycles_per_s"] = _rate_isasim(prog, n, reps)
     if not prog.has_global:
-        pal = s.engine("pallas", interpret=True).m
-        row["pallas_interpret_vcycles_per_s"] = _rate_machine(pal, n, reps)
+        pal = s.engine("pallas").m
+        row["pallas_vcycles_per_s"] = _rate_machine(pal, n, reps)
     else:
-        row["pallas_interpret_vcycles_per_s"] = None
+        row["pallas_vcycles_per_s"] = None
 
     # bit-exactness of the fast path against the seed engine
     st_new = new.run(new.init_state(), b.n_cycles + 10)

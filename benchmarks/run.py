@@ -11,6 +11,8 @@ from __future__ import annotations
 import sys
 import time
 
+from repro.jax_cache import configure_compile_cache
+
 MODULES = [
     "bench_engine",       # engine Vcycles/sec trajectory (jnp/pallas/isasim)
     "bench_batch",        # batched-stimulus aggregate Vcycles/sec vs B
@@ -29,6 +31,7 @@ MODULES = [
 
 
 def main() -> None:
+    configure_compile_cache()
     want = [a for a in sys.argv[1:] if not a.startswith("-")]
     failures = 0
     for mod in MODULES:

@@ -1,6 +1,6 @@
 """Static BSP executor — vectorized lockstep interpretation of a Program.
 
-TPU adaptation of the Manticore grid (DESIGN.md §2): core *c* of the paper's
+TPU adaptation of the Manticore grid: core *c* of the paper's
 MIMD grid becomes lane *c* of ``[C]``-wide vectors. Every slot, all lanes
 execute their own instruction simultaneously (compute-all-select over the
 opcode — NOp lanes are masked), which is exactly the paper's lockstep
@@ -105,9 +105,9 @@ def _alu_branches(ops, v1, v2, v3, v4, imm, lut_tt=None, ld_val=None,
     """(op, value) branch list for every result-producing opcode in ``ops``
     — the single definition of the ALU semantics, shared by the scan/window
     engines and the unrolled fast path. Operand shapes propagate ([C] or
-    [W, C]); ``lut_tt`` is the pre-gathered [..., 16] truth table,
-    ``ld_val``/``gld_val`` the pre-gathered memory reads (required iff
-    LUT/LD/GLD is in ``ops``)."""
+    [W, C]); ``lut_tt(p)`` returns the pre-gathered truth-table word of
+    pattern ``p``, ``ld_val``/``gld_val`` are the pre-gathered memory reads
+    (required iff LUT/LD/GLD is in ``ops``)."""
     branches = []
 
     def b(o, thunk):
@@ -148,7 +148,7 @@ def _alu_branches(ops, v1, v2, v3, v4, imm, lut_tt=None, ld_val=None,
         for p in range(16):
             m = (v1 if p & 1 else nv[0]) & (v2 if p & 2 else nv[1]) \
                 & (v3 if p & 4 else nv[2]) & (v4 if p & 8 else nv[3])
-            lut_out = lut_out | (m & lut_tt[..., p])
+            lut_out = lut_out | (m & lut_tt(p))
         branches.append((Op.LUT, lut_out))
     if Op.LD in ops:
         branches.append((Op.LD, ld_val))
@@ -221,8 +221,10 @@ def make_window_step(luts, spad_words, gmem_words, cache_lines, line_words,
         v3 = regs[col, instr[..., 4]] if need_v3 else zero
         v4 = regs[col, instr[..., 5]] if need_v4 else zero
 
-        lut_tt = (luts[col, jnp.minimum(imm, luts.shape[1] - 1)]
-                  if Op.LUT in ops else None)                 # [W, C, 16]
+        lut_tt = None
+        if Op.LUT in ops:
+            tt = luts[col, jnp.minimum(imm, luts.shape[1] - 1)]  # [W, C, 16]
+            lut_tt = lambda p: tt[..., p]
         ld_val = spads[col, v1 % spad_words] if Op.LD in ops else None
         if has_global:
             g_addr = ((v1 << 16) | v2) % gmem_words
@@ -319,7 +321,7 @@ class Machine:
     """
 
     def __init__(self, program: Program, backend: str = "jnp",
-                 compact: bool = True, interpret: bool = True,
+                 compact: bool = True, interpret: Optional[bool] = None,
                  specialize: bool = True, chunk: int = DEFAULT_CHUNK):
         self.p = program
         self.backend = backend
@@ -669,9 +671,10 @@ class Machine:
             v3 = regs[c_arr, lane[:, 4]] if need_v3 else None
             v4 = regs[c_arr, lane[:, 5]] if need_v4 else None
 
-            lut_tt = (self.luts[c_arr,
-                                np.minimum(imm, self.luts.shape[1] - 1)]
-                      if Op.LUT in wops else None)
+            lut_tt = None
+            if Op.LUT in wops:
+                tt = self.luts[c_arr, np.minimum(imm, self.luts.shape[1] - 1)]
+                lut_tt = lambda p, tt=tt: tt[..., p]
             ld_val = spads[c_arr, v1 % S] if Op.LD in wops else None
             gld_val = (gmem[((v1 << 16) | v2) % G]
                        if Op.GLD in wops else None)
@@ -849,7 +852,7 @@ class BatchedMachine(Machine):
     """
 
     def __init__(self, program: Program, images=None, batch: Optional[int] = None,
-                 backend: str = "jnp", interpret: bool = True,
+                 backend: str = "jnp", interpret: Optional[bool] = None,
                  compact: bool = True, chunk: int = DEFAULT_CHUNK):
         # build the jnp machinery (windows/unroll metadata) on the base
         # Machine; the pallas backend swaps in the batched chunk kernel below
@@ -1040,7 +1043,7 @@ class ShardedBatchedMachine(BatchedMachine):
 
     def __init__(self, program: Program, images=None,
                  batch: Optional[int] = None, devices=None,
-                 backend: str = "jnp", interpret: bool = True,
+                 backend: str = "jnp", interpret: Optional[bool] = None,
                  compact: bool = True, chunk: int = DEFAULT_CHUNK):
         super().__init__(program, images=images, batch=batch,
                          backend="jnp", interpret=interpret,

@@ -1,26 +1,16 @@
-"""Version compatibility shims for JAX APIs that moved between releases.
+"""Uniform ``shard_map`` front-end.
 
-``shard_map`` graduated from ``jax.experimental.shard_map`` to ``jax.shard_map``
-(and its replication-check kwarg was renamed ``check_rep`` -> ``check_vma``).
-This repo targets whichever the installed JAX provides; all internal users
-(``core.grid``, ``distributed.overlap``) import from here.
+``shard_map`` lives at ``jax.shard_map`` (its replication check is the
+``check_vma`` keyword). All internal users (``core.grid``, ``core.bsp``,
+``distributed.overlap``) import it from here, so the keyword default is
+set in one place.
 """
 from __future__ import annotations
 
 import jax
 
-try:                                       # jax >= 0.5: top-level API
-    _shard_map = jax.shard_map
-    _VMA_KWARG = True
-except AttributeError:                     # jax 0.4.x: experimental module
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _VMA_KWARG = False
-
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = False):
-    """Uniform shard_map front-end over old/new JAX APIs."""
-    if _VMA_KWARG:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=check_vma)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+    """``jax.shard_map`` with the replication check off by default."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

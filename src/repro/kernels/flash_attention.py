@@ -25,6 +25,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from .ops import resolve_interpret
+
 DEFAULT_BQ = 256
 DEFAULT_BK = 512
 _NEG = -1e30
@@ -74,7 +76,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True,
                     block_q: int = DEFAULT_BQ, block_k: int = DEFAULT_BK,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: Optional[bool] = None) -> jax.Array:
     """q, k, v: [BH, S, dh] -> [BH, S, dh]. S % block_q == S % block_k == 0.
     (GQA callers fold batch x heads into BH and repeat K/V per group.)"""
     BH, S, dh = q.shape
@@ -94,5 +96,5 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, block_q, dh), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S, dh), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

@@ -14,7 +14,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from ..configs import ARCHS, SMOKE
 from ..data.pipeline import PipelineConfig, TokenPipeline
@@ -42,7 +42,8 @@ def main() -> None:
     cfg = (SMOKE if args.smoke else ARCHS)[args.arch]
     n_dev = len(jax.devices())
     mp = args.model_parallel
-    mesh = jax.make_mesh((n_dev // mp, mp), ("data", "model"))
+    mesh = jax.make_mesh((n_dev // mp, mp), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     print(f"arch={cfg.name} devices={n_dev} mesh=({n_dev // mp},{mp})")
 
     model, step, p_shapes, p_specs, opt_shapes, o_specs = \

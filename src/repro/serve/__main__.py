@@ -32,6 +32,7 @@ import signal
 import sys
 from typing import List
 
+from ..jax_cache import configure_compile_cache
 from .batcher import BatchPolicy
 from .daemon import RetryPolicy, SimServer
 from .faults import FaultPlan
@@ -252,6 +253,7 @@ async def _run_drill(args: argparse.Namespace) -> int:
 
 async def _main() -> int:
     args = _args()
+    configure_compile_cache()
     if args.chaos_drill > 0:
         return await _run_drill(args)
     server = _server(args)

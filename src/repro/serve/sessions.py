@@ -448,6 +448,9 @@ class SessionManager:
         return {
             "counters": dict(self.counters),
             "resident": len(self._sessions),
+            # hot engines across sessions: one trace and device compile
+            # per (session, engine kind, batch size)
+            "engines": sum(len(s._engines) for s in self._sessions.values()),
             "nbytes": self.nbytes(),
             "breakers": {
                 f"{ident[0]}/{ident[1]}": br.snapshot()

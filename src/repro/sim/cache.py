@@ -16,7 +16,9 @@ The cache key is a SHA-256 over:
 * the compiler options (``strategy``, ``use_luts``, ``optimize``,
   ``sched_strategy``, ``placement``, ``pipeline``),
 * the artifact :data:`~repro.sim.artifact.FORMAT_VERSION` (a schema bump
-  silently invalidates old entries — they just miss).
+  silently invalidates old entries — they just miss),
+* :func:`source_digest` of the ``repro`` package sources, so a Program
+  that other compiler code produced is never served.
 
 Entries are ordinary :mod:`repro.sim.artifact` files named ``<key>.npz``
 under the cache directory (``REPRO_SIM_CACHE`` env var, default
@@ -27,6 +29,7 @@ rows) key on.
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 import json
 import os
@@ -41,9 +44,23 @@ from .artifact import FORMAT_VERSION, load_program, save_program
 
 ENV_VAR = "REPRO_SIM_CACHE"
 
+PACKAGE_ROOT = Path(__file__).resolve().parents[1]        # src/repro
+
 
 def default_cache_dir() -> Path:
     return Path(os.environ.get(ENV_VAR, "~/.cache/repro-sim")).expanduser()
+
+
+@functools.lru_cache(maxsize=1)
+def source_digest() -> str:
+    """SHA-256 over the path and bytes of every Python source of the
+    ``repro`` package (read once per process)."""
+    h = hashlib.sha256()
+    for path in sorted(PACKAGE_ROOT.rglob("*.py")):
+        h.update(path.relative_to(PACKAGE_ROOT).as_posix().encode("utf-8"))
+        h.update(b"\0")
+        h.update(path.read_bytes())
+    return h.hexdigest()
 
 
 def cache_key(circuit: Optional[Circuit], hw: HardwareConfig, *,
@@ -57,6 +74,7 @@ def cache_key(circuit: Optional[Circuit], hw: HardwareConfig, *,
     circuit is fingerprinted here."""
     payload = json.dumps({
         "format_version": FORMAT_VERSION,
+        "source": source_digest(),
         "circuit": fingerprint or circuit.fingerprint(),
         "hw": asdict(hw),
         "strategy": strategy,

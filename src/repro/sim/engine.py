@@ -113,7 +113,7 @@ class MachineEngine:
     batch = 1
 
     def __init__(self, program: Program, *, backend: str = "jnp",
-                 specialize: bool = True, interpret: bool = True,
+                 specialize: bool = True, interpret: Optional[bool] = None,
                  compact: bool = True, chunk: int = DEFAULT_CHUNK,
                  images: Optional[Images] = None):
         self.program = program
@@ -157,7 +157,7 @@ class BatchedEngine:
     def __init__(self, program: Program, *,
                  images: Optional[Sequence[Images]] = None,
                  batch: Optional[int] = None, backend: str = "jnp",
-                 interpret: bool = True, compact: bool = True,
+                 interpret: Optional[bool] = None, compact: bool = True,
                  chunk: int = DEFAULT_CHUNK):
         self.program = program
         self.m = BatchedMachine(program, images=images, batch=batch,
@@ -215,7 +215,7 @@ class ShardedBatchedEngine(BatchedEngine):
     def __init__(self, program: Program, *,
                  images: Optional[Sequence[Images]] = None,
                  batch: Optional[int] = None, devices=None,
-                 backend: str = "jnp", interpret: bool = True,
+                 backend: str = "jnp", interpret: Optional[bool] = None,
                  compact: bool = True, chunk: int = DEFAULT_CHUNK):
         self.program = program
         self.m = ShardedBatchedMachine(

@@ -1,6 +1,6 @@
 """Pallas vcycle kernel: shape sweeps + random-program allclose vs ref.py.
 
-The kernel runs in interpret mode (no TPU in this container); equivalence is
+The kernel runs in interpret mode on the CPU backend; equivalence is
 bit-exact (uint16 semantics), checked against both the pure-jnp oracle
 (kernels/ref.py) and the numpy ISA simulator on compiled programs.
 """
@@ -87,6 +87,16 @@ else:
     @pytest.mark.skip(reason="hypothesis not installed in this environment")
     def test_kernel_matches_ref_property():
         pass
+
+
+def test_interpret_resolves_from_platform():
+    """interpret=None means interpret mode exactly on a CPU backend; an
+    explicit value wins."""
+    import jax
+    from repro.kernels.ops import resolve_interpret
+    assert resolve_interpret() == (jax.default_backend() == "cpu")
+    assert resolve_interpret(False) is False
+    assert resolve_interpret(True) is True
 
 
 def test_ref_matches_isasim_on_compiled_program():

@@ -60,10 +60,11 @@ def test_sharded_train_step_executes():
         from repro.distributed import sharding as SH
         from repro.data.pipeline import PipelineConfig, TokenPipeline
         from repro.optim import adamw
-        from jax.sharding import NamedSharding
+        from jax.sharding import AxisType, NamedSharding
 
         cfg = SMOKE["qwen3-0.6b"]
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         model, step, p_shapes, p_specs, opt_shapes, o_specs = \\
             make_train_step(cfg, mesh)
         params = model.init(jax.random.key(0))
@@ -92,9 +93,11 @@ def test_sharded_decode_executes():
         from repro.configs import SMOKE
         from repro.launch.steps import make_serve_steps
         from repro.distributed import sharding as SH
+        from jax.sharding import AxisType
 
         cfg = SMOKE["mixtral-8x7b"]
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         model, prefill, decode, p_shapes, p_specs = \\
             make_serve_steps(cfg, mesh)
         params = model.init(jax.random.key(0))
@@ -122,9 +125,11 @@ def test_multipod_mesh_spec_resolution():
         import numpy as np, jax, jax.numpy as jnp
         from repro.configs import SMOKE
         from repro.launch.steps import lower_train
+        from jax.sharding import AxisType
 
         cfg = SMOKE["qwen3-1.7b"]
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                             axis_types=(AxisType.Auto,) * 3)
         lowered, model = lower_train(cfg, mesh, seq_len=32, global_batch=8)
         compiled = lowered.compile()
         txt = compiled.as_text()

@@ -159,6 +159,18 @@ def test_cache_key_sensitivity(tmp_path):
     assert sim.cache_key(b3.circuit, HW) != k
 
 
+def test_cache_entry_from_other_sources_is_a_miss(tmp_path, monkeypatch):
+    """An entry that other compiler sources wrote is never served: the
+    source digest is part of the key."""
+    import repro.sim.cache as cache
+    cold = sim.compile("mc", HW, scale="small", cache=tmp_path)
+    assert sim.compile("mc", HW, scale="small", cache=tmp_path).cache_hit
+    monkeypatch.setattr(cache, "source_digest", lambda: "other sources")
+    again = sim.compile("mc", HW, scale="small", cache=tmp_path)
+    assert not again.cache_hit
+    assert again.meta["cache_key"] != cold.meta["cache_key"]
+
+
 def test_corrupt_cache_entry_is_a_miss(tmp_path):
     cold = sim.compile("mc", HW, scale="small", cache=tmp_path)
     entry = sim.CompileCache(tmp_path).path(cold.meta["cache_key"])
