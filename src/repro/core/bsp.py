@@ -51,6 +51,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..distributed.compat import shard_map
+from ..spans import span, to_host
 from .compile import Program
 from .isa import Op
 
@@ -295,18 +296,29 @@ def make_window_step(luts, spad_words, gmem_words, cache_lines, line_words,
     return step
 
 
+def jit_chunk(fn):
+    """``jax.jit`` of a chunk function ``fn(cyc, budget, carry)`` under the
+    one name ``sim_chunk``: every engine's chunk program is the XLA module
+    ``jit_sim_chunk``, which a profiler trace finds by that name."""
+    def sim_chunk(cyc, budget, carry):
+        return fn(cyc, budget, carry)
+    sim_chunk.__wrapped__ = fn
+    return jax.jit(sim_chunk)
+
+
 def dispatch_chunks(run_chunk, cyc, carry, chunk: int, num_cycles: int,
                     done):
     """Host side of the chunked K-Vcycle dispatch, shared by the single,
     batched and multi-device engines: launch ceil(num_cycles/chunk)
     chunks, reading the exception flags once per chunk (the only host
     sync point) and stopping early when ``done(flags)``."""
-    budget = jnp.int32(num_cycles)
-    n_launch = -(-num_cycles // chunk) if num_cycles > 0 else 0
-    for _ in range(n_launch):
-        cyc, carry = run_chunk(cyc, budget, carry)
-        if done(np.asarray(carry[3])):
-            break
+    with span("sim.dispatch"):
+        budget = jnp.int32(num_cycles)
+        n_launch = -(-num_cycles // chunk) if num_cycles > 0 else 0
+        for _ in range(n_launch):
+            cyc, carry = run_chunk(cyc, budget, carry)
+            if done(to_host(carry[3])):
+                break
     return carry
 
 
@@ -464,9 +476,9 @@ class Machine:
                     program, C, interpret=interpret)
         if specialize:
             if backend == "pallas":
-                self._run_chunk = jax.jit(self._chunk_kernel)
+                self._run_chunk = jit_chunk(self._chunk_kernel)
             else:
-                self._run_chunk = jax.jit(self._chunk_impl)
+                self._run_chunk = jit_chunk(self._chunk_impl)
         else:
             self._run = jax.jit(self._run_legacy,
                                 static_argnames=("num_cycles",))
@@ -798,12 +810,12 @@ class Machine:
         return MachineState(*carry)
 
     def exceptions(self, state: MachineState) -> Dict[int, int]:
-        f = np.asarray(state.flags)
+        f = to_host(state.flags)
         return {int(c): int(e) for c, e in enumerate(f) if e}
 
     def read_output(self, state: MachineState, name: str) -> int:
         core, mregs = self.p.outputs[name]
-        regs = np.asarray(state.regs)
+        regs = to_host(state.regs)
         out = 0
         for j, r in enumerate(mregs):
             out |= int(regs[core, r]) << (16 * j)
@@ -811,7 +823,7 @@ class Machine:
 
     def read_reg(self, state: MachineState, rtl_name: str) -> int:
         words = self.p.state_regs[rtl_name]
-        regs = np.asarray(state.regs)
+        regs = to_host(state.regs)
         out = 0
         for j, locs in enumerate(words):
             c, r = locs[0]
@@ -819,7 +831,7 @@ class Machine:
         return out
 
     def perf(self, state: MachineState) -> Dict[str, int]:
-        cnt = np.asarray(state.counters)
+        cnt = to_host(state.counters)
         vcycles = int(cnt[0])
         stalls = int(cnt[3])
         return {
@@ -865,12 +877,12 @@ class BatchedMachine(Machine):
         self._plain = backend != "pallas" and B == 1
         if backend == "pallas":
             from ..kernels import ops as kops
-            self._run_chunk = jax.jit(kops.make_vcycle_chunk(
+            self._run_chunk = jit_chunk(kops.make_vcycle_chunk(
                 program, self.C, self.chunk, interpret=interpret, batch=B))
         elif self._plain:
-            self._run_chunk = jax.jit(self._b1chunk_impl)
+            self._run_chunk = jit_chunk(self._b1chunk_impl)
         else:
-            self._run_chunk = jax.jit(self._bchunk_impl)
+            self._run_chunk = jit_chunk(self._bchunk_impl)
 
     # ------------------------------------------------------------------
     def _set_images(self, images, batch: Optional[int]) -> None:
@@ -993,7 +1005,7 @@ class BatchedMachine(Machine):
     def perf(self, state: MachineState, b: Optional[int] = None):
         if b is not None:
             return super().perf(self.element(state, b))
-        cnt = np.asarray(state.counters)
+        cnt = to_host(state.counters)
         vcycles = int(cnt[:, 0].sum())
         stalls = int(cnt[:, 3].sum())
         return {
@@ -1084,7 +1096,7 @@ class ShardedBatchedMachine(BatchedMachine):
             device_chunk, self.mesh,
             in_specs=(lead(), P()) + state_specs,
             out_specs=(lead(), lead()) + state_specs)
-        self._run_chunk = jax.jit(
+        self._run_chunk = jit_chunk(
             lambda cyc, budget, carry: sharded(cyc, budget, *carry))
 
     # ------------------------------------------------------------------
@@ -1129,11 +1141,12 @@ class ShardedBatchedMachine(BatchedMachine):
         budget = jnp.int32(num_cycles)
         n_launch = -(-int(num_cycles) // self.chunk) if num_cycles > 0 else 0
         carry = tuple(state)
-        for _ in range(n_launch):
-            cyc, frozen, *carry = self._run_chunk(cyc, budget, carry)
-            carry = tuple(carry)
-            if np.asarray(frozen).all():
-                break
+        with span("sim.dispatch"):
+            for _ in range(n_launch):
+                cyc, frozen, *carry = self._run_chunk(cyc, budget, carry)
+                carry = tuple(carry)
+                if to_host(frozen).all():
+                    break
         return MachineState(*carry)
 
     def perf(self, state: MachineState, b: Optional[int] = None):

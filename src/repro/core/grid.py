@@ -42,7 +42,9 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..distributed.compat import shard_map
-from .bsp import DEFAULT_CHUNK, MachineState, dispatch_chunks, make_slot_step
+from ..spans import to_host
+from .bsp import (DEFAULT_CHUNK, MachineState, dispatch_chunks, jit_chunk,
+                  make_slot_step)
 from .compile import Program
 
 
@@ -260,7 +262,6 @@ class GridMachine:
             def active_of(cyc, budget, st):
                 return (cyc < budget) & ~jnp.any(st[3] != 0, axis=1)  # [B]
 
-        @jax.jit
         def run_chunk(cyc, budget, state):
             def body(c, _):
                 cyc, st = c
@@ -276,7 +277,7 @@ class GridMachine:
                                            length=self.chunk)
             return cyc, state
 
-        self._run_chunk = run_chunk
+        self._run_chunk = jit_chunk(run_chunk)
 
     # ------------------------------------------------------------------
     def init_state(self) -> MachineState:
@@ -315,13 +316,13 @@ class GridMachine:
         one dict per batch element (mirroring BatchedMachine)."""
         if self.B is not None and b is None:
             return [self.exceptions(state, i) for i in range(self.B)]
-        f = np.asarray(self._elem(state.flags, b))[:self.C]
+        f = to_host(self._elem(state.flags, b))[:self.C]
         return {int(c): int(e) for c, e in enumerate(f) if e}
 
     def read_reg(self, state: MachineState, rtl_name: str,
                  b: Optional[int] = None) -> int:
         words = self.p.state_regs[rtl_name]
-        regs = np.asarray(self._elem(state.regs, b))
+        regs = to_host(self._elem(state.regs, b))
         out = 0
         for j, locs in enumerate(words):
             c, r = locs[0]
@@ -331,7 +332,7 @@ class GridMachine:
     def read_output(self, state: MachineState, name: str,
                     b: Optional[int] = None) -> int:
         core, mregs = self.p.outputs[name]
-        regs = np.asarray(self._elem(state.regs, b))
+        regs = to_host(self._elem(state.regs, b))
         out = 0
         for j, r in enumerate(mregs):
             out |= int(regs[core, r]) << (16 * j)
@@ -342,9 +343,9 @@ class GridMachine:
         """Performance counters (device 0 holds the privileged core). With
         batched state and ``b=None``, aggregates over the batch."""
         if self.B is not None and b is None:
-            cnt = np.asarray(state.counters)[:, 0].sum(axis=0)
+            cnt = to_host(state.counters)[:, 0].sum(axis=0)
         else:
-            cnt = np.asarray(self._elem(state.counters, b))[0]
+            cnt = to_host(self._elem(state.counters, b))[0]
         return {
             "vcycles": int(cnt[0]),
             "ghits": int(cnt[1]),

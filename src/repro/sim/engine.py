@@ -29,6 +29,7 @@ from ..core.compile import Program
 from ..core.interpreter import NetlistSim
 from ..core.isasim import IsaSim
 from ..core.netlist import Circuit
+from ..spans import count, span, to_host
 from .result import ORACLE_CORE, RunResult
 
 Images = Tuple[np.ndarray, np.ndarray, np.ndarray]
@@ -87,17 +88,20 @@ def _snapshot(eng, b: int) -> RunResult:
     host-visible output the program kept, plus exceptions and counters.
     The register file is pulled off-device once per snapshot (not once
     per probe — ``read_reg`` on the raw engines transfers per call)."""
-    prog: Program = eng.program
-    regs = eng._regs_np(b)
-    perf = dict(eng.perf(b))
-    return RunResult(
-        cycles=int(perf["vcycles"]),
-        exceptions=dict(eng.exceptions(b)),
-        perf=perf,
-        registers=_probe_registers(prog, regs),
-        outputs=_probe_outputs(prog, regs),
-        batch_index=b,
-    )
+    with span("sim.snapshot"):
+        prog: Program = eng.program
+        regs = eng._regs_np(b)
+        perf = dict(eng.perf(b))
+        result = RunResult(
+            cycles=int(perf["vcycles"]),
+            exceptions=dict(eng.exceptions(b)),
+            perf=perf,
+            registers=_probe_registers(prog, regs),
+            outputs=_probe_outputs(prog, regs),
+            batch_index=b,
+        )
+    count("sim.snapshots")
+    return result
 
 
 class MachineEngine:
@@ -124,7 +128,8 @@ class MachineEngine:
         self.reset()
 
     def reset(self) -> None:
-        self.state = self.m.init_state(self._images)
+        with span("sim.stage"):
+            self.state = self.m.init_state(self._images)
 
     def run(self, num_cycles: int) -> RunResult:
         self.state = self.m.run(self.state, num_cycles)
@@ -134,7 +139,7 @@ class MachineEngine:
         return [self.run(num_cycles)]
 
     def _regs_np(self, b: int) -> np.ndarray:
-        return np.asarray(self.state.regs)
+        return to_host(self.state.regs)
 
     def read_reg(self, name: str, b: int = 0) -> int:
         return self.m.read_reg(self.state, name)
@@ -167,16 +172,18 @@ class BatchedEngine:
         self.reset()
 
     def reset(self) -> None:
-        self.state = self.m.init_state()
+        with span("sim.stage"):
+            self.state = self.m.init_state()
 
     def rebind(self, images) -> None:
         """Swap this engine onto a new batch of stimuli (same B) and
         reset. The underlying machine keeps its traced/jitted Vcycle
         dispatch (``BatchedMachine.rebind_images``), so a serving layer
         can reuse one hot engine across successive coalesced batches."""
-        self.m.rebind_images(images)
-        self.batch = self.m.B
-        self.reset()
+        with span("sim.stage"):
+            self.m.rebind_images(images)
+            self.batch = self.m.B
+            self.reset()
 
     def run(self, num_cycles: int) -> RunResult:
         self.state = self.m.run(self.state, num_cycles)
@@ -187,7 +194,7 @@ class BatchedEngine:
         return [_snapshot(self, b) for b in range(self.batch)]
 
     def _regs_np(self, b: int) -> np.ndarray:
-        return np.asarray(self.state.regs[b])
+        return to_host(self.state.regs[b])
 
     def read_reg(self, name: str, b: int = 0) -> int:
         return self.m.read_reg(self.state, name, b)
@@ -247,7 +254,8 @@ class GridEngine:
         self.reset()
 
     def reset(self) -> None:
-        self.state = self.m.init_state()
+        with span("sim.stage"):
+            self.state = self.m.init_state()
 
     def run(self, num_cycles: int) -> RunResult:
         self.state = self.m.run(self.state, num_cycles)
@@ -261,7 +269,7 @@ class GridEngine:
         return b if self._batched else None
 
     def _regs_np(self, b: int) -> np.ndarray:
-        return np.asarray(self.m._elem(self.state.regs, self._b(b)))
+        return to_host(self.m._elem(self.state.regs, self._b(b)))
 
     def read_reg(self, name: str, b: int = 0) -> int:
         return self.m.read_reg(self.state, name, self._b(b))
@@ -294,13 +302,15 @@ class IsaEngine:
         self.reset()
 
     def reset(self) -> None:
-        self.sim = IsaSim(self.program)
-        if self._images is not None:
-            ri, si, gi = self._images
-            C, R = self.sim.C, self.sim.R
-            self.sim.regs = np.asarray(ri)[:C, :R].astype(np.uint32).copy()
-            self.sim.spads = np.asarray(si)[:C].astype(np.uint32).copy()
-            self.sim.gmem = np.asarray(gi).astype(np.uint32).copy()
+        with span("sim.stage"):
+            self.sim = IsaSim(self.program)
+            if self._images is not None:
+                ri, si, gi = self._images
+                C, R = self.sim.C, self.sim.R
+                self.sim.regs = \
+                    np.asarray(ri)[:C, :R].astype(np.uint32).copy()
+                self.sim.spads = np.asarray(si)[:C].astype(np.uint32).copy()
+                self.sim.gmem = np.asarray(gi).astype(np.uint32).copy()
 
     def run(self, num_cycles: int) -> RunResult:
         self.sim.run(num_cycles)
@@ -347,9 +357,10 @@ class OracleEngine:
         self.reset()
 
     def reset(self) -> None:
-        self.sim = NetlistSim(self.circuit)
-        self._exc: List[int] = []
-        self._outputs: Dict[str, int] = {}
+        with span("sim.stage"):
+            self.sim = NetlistSim(self.circuit)
+            self._exc: List[int] = []
+            self._outputs: Dict[str, int] = {}
 
     def run(self, num_cycles: int) -> RunResult:
         for _ in range(num_cycles):

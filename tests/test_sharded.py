@@ -225,7 +225,7 @@ def test_batched_b1_skips_vmap(mc_small):
     images = b.images(prog)
     bm = BatchedMachine(prog, images=images[:1])
     assert bm._plain
-    assert bm._run_chunk.__wrapped__.__func__ is \
+    assert bm._run_chunk.__wrapped__.__wrapped__.__func__ is \
         BatchedMachine._b1chunk_impl
     st = bm.run(bm.init_state(), b.n_cycles + 10)
     m = Machine(prog)
