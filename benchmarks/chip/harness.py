@@ -19,7 +19,9 @@ compile or cache load, a warm-up through every chunk of a launch), then
 the measured window of whole launches, then the comparison with the plain
 reference (``reference.py``, ``compare.py``), which no metric counts. With
 ``trace`` the window runs under the JAX profiler and the per-layer metrics
-are read from it; without, the end-to-end metrics.
+are read from it, from the engines' own spans in it (``engine_spans.py``)
+and from the program's counters over the window; without, the end-to-end
+metrics.
 """
 from __future__ import annotations
 
@@ -33,7 +35,7 @@ from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
 from pathlib import Path
 from types import ModuleType
-from typing import Callable, Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -127,6 +129,9 @@ class TraceSummary:
     busy_ns: List[float]            # per device, inside the traced window
     window_ns: float                # first harness span to the last
     breakdown: dict
+    # the engines' ``sim.*`` spans in the window: {name: (seconds, events)}
+    spans: Dict[str, Tuple[float, int]] = field(default_factory=dict)
+    chunk_busy_ns: List[float] = field(default_factory=list)  # per device
 
 
 @dataclass
@@ -143,6 +148,8 @@ class Run:
     trace: Optional[TraceSummary] = None
     device: dict = field(default_factory=dict)
     counts: Dict[str, float] = field(default_factory=dict)
+    # the program's counters (``repro.spans``) over the window alone
+    counters: Dict[str, int] = field(default_factory=dict)
 
     @property
     def window_cycles(self) -> int:
@@ -255,9 +262,21 @@ def find_devices(chips: int) -> list:
     return devs[:chips]
 
 
+def program_counters() -> Dict[str, int]:
+    """The program's counters (``repro.spans``); empty where it keeps
+    none."""
+    try:
+        from repro.spans import counters
+    except ImportError:
+        return {}
+    return counters()
+
+
 def _summarize_trace(log_dir: Path) -> Optional[TraceSummary]:
-    """Busy time and breakdown of the traced window, which runs from the
-    first harness span to the last; None where the trace holds no TPU."""
+    """Busy time, the engines' spans and chunk program, and the breakdown
+    of the traced window, which runs from the first harness span to the
+    last; None where the trace holds no TPU."""
+    import engine_spans
     import tracing
     tr = tracing.load(tracing.find_xplane(log_dir), SPAN_NAMES)
     spans = sorted(sp for name in SPAN_NAMES for sp in tr.spans(name))
@@ -268,7 +287,11 @@ def _summarize_trace(log_dir: Path) -> Optional[TraceSummary]:
         busy_ns=[tracing.busy_ns(d, lo, hi) for d in tr.devices],
         window_ns=hi - lo,
         breakdown={"device_ops": tracing.top_ops(tr, lo, hi),
-                   "idle_gaps": tracing.idle_by_host(tr, lo, hi)})
+                   "idle_gaps": tracing.idle_by_host(tr, lo, hi),
+                   "idle_by_span": engine_spans.idle_by_span(tr, lo, hi)},
+        spans=engine_spans.span_totals(tr, lo, hi),
+        chunk_busy_ns=[engine_spans.module_busy_ns(d, lo, hi)
+                       for d in tr.devices])
 
 
 def reference_of(bench, cycles: int, arith: str = "exact"):
@@ -366,8 +389,11 @@ def execute(cell: dict, config: dict, traffic: dict, seed: int,
             str(log_dir), profiler_options=_profile_options()) \
             if trace else nullcontext()
         with ctx:
+            before = program_counters()
             closed_loop_launches(run, prep.engine, prep.images, seconds,
                                  say)
+            run.counters = {k: v - before.get(k, 0)
+                            for k, v in program_counters().items()}
         run.window_compiles = prep.counter.n - compiles0
         say(f"[window] launches={len(run.launches)} seconds="
             f"{run.window_s:.6f} cycles={run.window_cycles} "

@@ -163,21 +163,32 @@ def test_broken_timed_path_is_not_correct(fault):
 
 FOUR_CHIPS = r"""
 import json, sys, time
-sys.path[:0] = sys.argv[1:3]
-import jax, numpy as np, harness
+sys.path[:0] = sys.argv[1:4]
+import jax, jax.numpy as jnp, harness
+from batch_demux import BatchDemux
 cell = harness.find_cell(harness.load_spec(), "mc-farm-4chip.4chip")
 cfg = dict(harness.load_config(cell["config"]), scale="small", params={},
            batch=16, budget_vcycles=44)
 traffic = harness.load_traffic(cell["traffic"])
 
 def one_chip_read(eng):
-    # the host reads every stimulus from the first chip's shard
-    per = eng.batch // 4
-    eng._regs_np = lambda b: np.asarray(eng.state.regs[b % per])
+    # every stimulus's registers are those of the stimulus at its
+    # position in the first chip's shard, in the state the engine returns
+    run, per = eng.m.run, eng.batch // 4
+
+    def wrong(state, n):
+        out = run(state, n)
+        rows = jnp.arange(out.regs.shape[0]) % per
+        return out._replace(regs=jax.device_put(out.regs[rows],
+                                                out.regs.sharding))
+    eng.m.run = wrong
     return eng
 
 out = {}
-for name, hook in (("sound", None), ("one_chip_read", one_chip_read)):
+for name, hook in (("sound", None), ("one_chip_read", one_chip_read),
+                   ("batch_demux", BatchDemux),
+                   ("one_chip_read_batch_demux",
+                    lambda eng: BatchDemux(one_chip_read(eng)))):
     run, v = harness.execute(cell, cfg, traffic, 77, 0.0, False,
                              jax.devices()[:4], time.perf_counter(),
                              lambda s: None, hook)
@@ -187,13 +198,19 @@ print(json.dumps(out))
 
 
 def test_four_chip_path_and_a_lost_cross_chip_read():
+    """On four host devices: the sharded engine is correct, and a fault
+    that gives every stimulus the first chip's registers is caught,
+    whether the engines' demux or a batch demux reads the results."""
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4")
     proc = subprocess.run(
-        [sys.executable, "-c", FOUR_CHIPS, str(BENCH), str(REPO / "src")],
+        [sys.executable, "-c", FOUR_CHIPS, str(HERE), str(BENCH),
+         str(REPO / "src")],
         env=env, capture_output=True, text=True, timeout=600)
     assert proc.returncode == 0, proc.stderr[-3000:]
     out = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert out["sound"] == [True, {"differ": 0, "unfinished": 0}, 0]
-    assert out["one_chip_read"][0] is False
-    assert out["one_chip_read"][1]["differ"] > 0
+    for sound in ("sound", "batch_demux"):
+        assert out[sound] == [True, {"differ": 0, "unfinished": 0}, 0]
+    for fault in ("one_chip_read", "one_chip_read_batch_demux"):
+        assert out[fault][0] is False
+        assert out[fault][1]["differ"] > 0
