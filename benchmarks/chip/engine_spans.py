@@ -49,11 +49,12 @@ def module_busy_ns(dev: tracing.DeviceTimeline, lo: float, hi: float,
                    module: str = CHUNK_MODULE) -> float:
     """Busy time of ``dev`` in [lo, hi] in operations of ``module``."""
     starts = [m[0] for m in dev.modules]
+    ours = [tracing.MODULE_HASH.sub("", m[2]) == module
+            for m in dev.modules]
 
     def ran_in_module(s):
         k = bisect.bisect_right(starts, s) - 1
-        return k >= 0 and dev.modules[k][1] >= s and \
-            tracing.MODULE_HASH.sub("", dev.modules[k][2]) == module
+        return k >= 0 and dev.modules[k][1] >= s and ours[k]
 
     ops = ((s, e) for s, e, _ in dev.ops if ran_in_module(s))
     return sum(e - s for s, e in tracing.union(ops, lo, hi))

@@ -17,11 +17,15 @@ lives in a file of its own beside this module, found by its name:
 A run: set-up (JAX start, bench build, host compile, stimulus images, XLA
 compile or cache load, a warm-up through every chunk of a launch), then
 the measured window of whole launches, then the comparison with the plain
-reference (``reference.py``, ``compare.py``), which no metric counts. With
-``trace`` the window runs under the JAX profiler and the per-layer metrics
-are read from it, from the engines' own spans in it (``engine_spans.py``)
-and from the program's counters over the window; without, the end-to-end
-metrics.
+reference (``reference.py``, ``compare.py``), which no metric counts.
+Without ``trace`` the window ends at the first launch boundary after
+``seconds`` and the run reports the end-to-end metrics. With ``trace`` the
+window is a slice: it ends at the first launch boundary after
+``TRACE_SECONDS`` (after ``seconds`` where that is shorter) and so holds
+one launch or more, runs under the JAX profiler, and the run reports the
+per-layer metrics, read from the trace, from the engines' own spans in it
+(``engine_spans.py``) and from the program's counters over the slice. The
+slice's launches are what the comparison checks.
 """
 from __future__ import annotations
 
@@ -49,6 +53,14 @@ SPAN_NAMES = ("rebind", "reset", "run_batch")
 RESTAGES = ("rebind", "reset")
 # the modelled Manticore's clock (paper §7): a count for reference only
 FPGA_HZ = 475e6
+# The length of a traced run's window, s. The device's trace holds one event
+# per operation run, so a trace of the whole window grows with the program's
+# speed, and its collection and reduction with it, past the time a run is
+# allowed. Every per-layer metric is a ratio over whole launches (per
+# stimulus-cycle, per stimulus, per launch, or a share of the window), so a
+# slice of whole launches reads what the whole window would, at a cost that
+# the slice sets: one launch of a slow program, a few of a fast one.
+TRACE_SECONDS = 2.0
 
 
 class NoChip(RuntimeError):
@@ -390,8 +402,9 @@ def execute(cell: dict, config: dict, traffic: dict, seed: int,
             if trace else nullcontext()
         with ctx:
             before = program_counters()
-            closed_loop_launches(run, prep.engine, prep.images, seconds,
-                                 say)
+            closed_loop_launches(run, prep.engine, prep.images,
+                                 min(seconds, TRACE_SECONDS) if trace
+                                 else seconds, say)
             run.counters = {k: v - before.get(k, 0)
                             for k, v in program_counters().items()}
         run.window_compiles = prep.counter.n - compiles0

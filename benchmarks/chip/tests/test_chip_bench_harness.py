@@ -1,6 +1,6 @@
 """CPU tests of the chip benchmark's harness: the spec, discovery by name,
-the metric arithmetic, the window's whole launches, and the refusal to
-run without a TPU."""
+the metric arithmetic, the window's whole launches and a traced run's
+slice of them, and the refusal to run without a TPU."""
 from __future__ import annotations
 
 import json
@@ -191,6 +191,78 @@ def test_a_mix_with_another_driver_is_added_as_files_only(
     assert harness.load_metric("sim_cycles_per_s").read(run) == \
         pytest.approx(config["batch"] * finish * len(run.launches)
                       / run.window_s)
+
+
+SLICE_FARM = dict(LONG_TEST, name="dummy-farm", design="mc",
+                  params={"n_walkers": 2, "n_cycles": 24}, batch=4,
+                  budget_vcycles=34)
+
+
+def _ends_at_the_first_boundary_after(run, seconds):
+    t0 = run.launches[-1].end - run.window_s
+    ends = [ln.end - t0 for ln in run.launches]
+    return ends[-1] >= seconds and all(e < seconds for e in ends[:-1])
+
+
+def _execute_farm(seconds, trace):
+    import jax
+    said = []
+    run, verdict = harness.execute(
+        {"name": "dummy.cell", "chips": 1}, SLICE_FARM,
+        {"restage": "rebind", "call_vcycles": None}, 2 ** 31 + 41, seconds,
+        trace, jax.devices()[:1], harness.time.perf_counter(), said.append)
+    return run, verdict, said
+
+
+@pytest.fixture(scope="module")
+def traced_slice():
+    """A traced run asked for the benchmark's 51-second window."""
+    return _execute_farm(51.0, True)
+
+
+def test_a_traced_window_is_a_slice_of_whole_launches(traced_slice):
+    """A traced run's window ends at the first launch boundary after
+    ``TRACE_SECONDS``, not after ``seconds``, and holds whole launches."""
+    run, verdict, said = traced_slice
+    assert harness.TRACE_SECONDS < 51.0
+    assert len(run.launches) >= 1
+    assert _ends_at_the_first_boundary_after(run, harness.TRACE_SECONDS)
+    assert run.window_s < 51.0
+    assert run.window_compiles == 0 and verdict.correct
+    assert f"launches={len(run.launches)} " in said[-1]
+
+
+def test_a_traced_slice_counts_and_compares_its_own_launches(traced_slice):
+    """The counters, the cycles and the comparison cover exactly the
+    slice's launches."""
+    run, verdict, _ = traced_slice
+    results = [r for ln in run.launches for r in ln.results]
+    assert run.counters["sim.snapshots"] == len(results) == \
+        SLICE_FARM["batch"] * len(run.launches)
+    assert run.window_cycles == sum(r.cycles for r in results) > 0
+    assert verdict.attempted == len(results) and verdict.failed == 0
+    assert harness.load_metric("sim_cycles_per_s").read(run) == \
+        pytest.approx(run.window_cycles / run.window_s)
+
+
+def test_an_untraced_window_still_runs_to_its_seconds():
+    """Without tracing the window runs to ``seconds``, here past
+    ``TRACE_SECONDS``."""
+    seconds = harness.TRACE_SECONDS + 0.5
+    run, verdict, _ = _execute_farm(seconds, False)
+    assert run.trace is None and verdict.correct
+    assert _ends_at_the_first_boundary_after(run, seconds)
+    assert verdict.attempted == SLICE_FARM["batch"] * len(run.launches)
+
+
+@pytest.mark.parametrize("seconds", [0.3, 1.0])
+def test_a_traced_window_shorter_than_the_slice_runs_to_its_seconds(
+        seconds):
+    """Where ``seconds`` is under ``TRACE_SECONDS`` it wins, as in the
+    tests' short traced runs."""
+    run, verdict, _ = _execute_farm(seconds, True)
+    assert _ends_at_the_first_boundary_after(run, seconds)
+    assert verdict.correct and len(run.launches) >= 1
 
 
 def test_unknown_names_are_errors(spec):

@@ -13,6 +13,7 @@ HERE = Path(__file__).resolve().parent
 BENCH = HERE.parent
 sys.path[:0] = [str(BENCH)]
 
+import engine_spans  # noqa: E402
 import tracing  # noqa: E402
 
 RECORDED = HERE / "data" / "tiny_v5e.xplane.pb"
@@ -121,3 +122,58 @@ def test_recorded_v5e_trace():
     top = dict(tracing.top_ops(tr, lo, hi))
     assert "jit__lambda: %add_remainder_fusion fusion" in top
     assert sum(top.values()) == pytest.approx(busy / 1e9)
+
+
+def test_recorded_reductions_are_unchanged_to_the_digit():
+    """The recorded trace's reductions, pinned to the digit: a cheaper
+    reduction has to give exactly what the plain one gives."""
+    tr = tracing.load(RECORDED)
+    assert len(tr.host) == 99
+    spans = sorted(tr.spans("rebind") + tr.spans("run_batch"))
+    lo, hi = spans[0][0], spans[-1][1]
+    assert (lo, hi) == (44719645.0, 150903363.0)
+    assert tracing.top_ops(tr, lo, hi) == [
+        ["jit__lambda: %add_remainder_fusion fusion", 3.163e-06],
+        ["jit__lambda: %copy-done copy-done", 1.506e-06],
+        ["jit__lambda: %reduce_sum.7 reduce", 1.052e-06],
+        ["jit_dynamic_slice: %dynamic_slice.1 dynamic-slice", 9.45e-07],
+        ["jit_dynamic_slice: %copy-start copy-start", 4.1e-08],
+        ["jit__lambda: %copy-start copy-start", 2.6e-08],
+        ["jit_dynamic_slice: %copy-done copy-done", 8e-09]]
+    assert tracing.idle_by_host(tr, lo, hi) == [
+        ["run_batch > backend_compile_and_load", 0.100281105],
+        ["run_batch > np.asarray(jax.Array)", 0.003375609],
+        ["run_batch > DevicePut", 0.002348348],
+        ["run_batch > ExecuteReplicated.__call__", 0.00017086],
+        ["run_batch > PjitFunction(dynamic_slice)", 3.54e-07],
+        ["rebind > PjitFunction(<lambda>)", 3.51e-07],
+        ["run_batch > PjitFunction(<lambda>)", 3.5e-07]]
+    assert engine_spans.span_totals(tr, lo, hi) == {}
+    assert engine_spans.idle_by_span(tr, lo, hi) == [
+        [engine_spans.OUTSIDE, 0.106176977]]
+    dev = tr.devices[0]
+    assert tracing.busy_ns(dev, lo, hi) == 6741.0
+    assert engine_spans.module_busy_ns(dev, lo, hi, "jit__lambda") == 5747.0
+
+
+class _Unread:
+    """A line whose events may not be read."""
+
+    def __init__(self, name):
+        self.name = name
+
+    @property
+    def events(self):
+        raise AssertionError(f"line {self.name!r} was read")
+
+
+def test_from_profile_reads_only_the_lines_it_keeps():
+    """A device's lines other than its operations and modules are never
+    read."""
+    pd = _profile()
+    dev0 = next(p for p in pd.planes if p.name == "/device:TPU:0")
+    dev0.lines.append(_Unread("Async XLA Ops"))
+    tr = tracing.from_profile(pd, ("rebind", "run_batch"))
+    assert [len(d.ops) for d in tr.devices] == [2, 1]
+    assert [n for _, _, n in tr.host] == ["rebind", "run_batch",
+                                          "np.asarray"]

@@ -65,20 +65,27 @@ def load(path: Path, span_names: Sequence[str] = ("rebind", "run_batch")
     return from_profile(ProfileData.from_file(str(path)), span_names)
 
 
+def _events(line) -> List[Tuple[float, float, str]]:
+    return [(e.start_ns, e.end_ns, e.name) for e in line.events]
+
+
 def from_profile(pd, span_names: Sequence[str]) -> Trace:
+    """Events are read only from the lines that are kept: each device's
+    operations and modules, and the host thread that holds any of
+    ``span_names``, found by its events' names before they are read."""
     devices, host = [], []
+    wanted = set(span_names)
     for plane in pd.planes:
         if DEVICE_PLANE.match(plane.name):
-            lines = {line.name: [(e.start_ns, e.end_ns, e.name)
-                                 for e in line.events] for line in plane.lines}
+            lines = {line.name: _events(line) for line in plane.lines
+                     if line.name in (OPS_LINE, MODULES_LINE)}
             devices.append(DeviceTimeline(
                 plane.name, sorted(lines.get(OPS_LINE, [])),
                 sorted(lines.get(MODULES_LINE, []))))
         elif plane.name == HOST_PLANE:
             for line in plane.lines:
-                events = [(e.start_ns, e.end_ns, e.name) for e in line.events]
-                if any(n in span_names for _, _, n in events):
-                    host.extend(events)
+                if any(e.name in wanted for e in line.events):
+                    host.extend(_events(line))
     devices.sort(key=lambda d: int(d.name.rsplit(":", 1)[1]))
     # outer events first where two start together, so that they nest
     return Trace(devices, sorted(host, key=lambda ev: (ev[0], -ev[1])))
@@ -192,18 +199,23 @@ def _self_times(ops: Sequence[Tuple[float, float, str]]):
 def top_ops(trace: Trace, lo: float, hi: float, top: int = 10
             ) -> List[List]:
     """Device time per operation in [lo, hi], each op counted for its own
-    time, in seconds averaged over the devices, longest first."""
+    time, in seconds averaged over the devices, longest first. An
+    operation's name is parsed once per distinct module and name."""
     total: Dict[str, float] = defaultdict(float)
     for dev in trace.devices:
         starts = [m[0] for m in dev.modules]
+        mods = [MODULE_HASH.sub("", m[2]) for m in dev.modules]
+        labels: Dict[Tuple[str, str], str] = {}
         for s, e, name, own in _self_times(dev.ops):
             if e <= lo or s >= hi:
                 continue
             own *= (min(e, hi) - max(s, lo)) / (e - s) if e > s else 0.0
             k = bisect.bisect_right(starts, s) - 1
-            mod = MODULE_HASH.sub("", dev.modules[k][2]) \
-                if k >= 0 and dev.modules[k][1] >= s else "?"
-            total[f"{mod}: {op_name(name)}"] += own
+            mod = mods[k] if k >= 0 and dev.modules[k][1] >= s else "?"
+            label = labels.get((mod, name))
+            if label is None:
+                label = labels[mod, name] = f"{mod}: {op_name(name)}"
+            total[label] += own
     n = max(len(trace.devices), 1)
     rows = sorted(total.items(), key=lambda kv: -kv[1])[:top]
     return [[name, ns / n / 1e9] for name, ns in rows]
